@@ -31,6 +31,7 @@ import argparse
 import json
 import sys
 
+from repro.launch import compile_cache
 from repro.profiling import (GridSpec, check_report, profile_kernels,
                              run_profile)
 
@@ -101,6 +102,7 @@ def main(argv=None) -> int:
         import dataclasses
         grid = dataclasses.replace(grid, **overrides)
 
+    compile_cache.enable()
     report = run_profile(grid, smoke=args.smoke, verbose=args.verbose)
     if args.kernels:
         report["kernels"] = profile_kernels(warmup=grid.warmup,

@@ -1,15 +1,17 @@
 """End-to-end driver: REAL model serving with batched requests under
 HAS-GPU resource control, plus the full simulated platform comparison.
 
-Part 1 serves an actual (reduced) qwen2.5 through the Gateway -> PodEngine
--> libhas token handshake on CPU, demonstrating vertical scaling speeding
-up a live pod. Part 2 replays an Azure-style trace through the cluster
-simulator for HAS vs KServe-like vs FaST-GShare-like.
+Part 1 serves a reduced qwen2.5 (2 layers, small widths, so it runs on
+any device) through the Gateway -> PodEngine -> libhas token handshake,
+demonstrating vertical scaling speeding up a live pod. ``chip_smoke.py``
+serves the published widths on a TPU. Part 2 replays an Azure-style trace
+through the cluster simulator for HAS vs KServe-like vs FaST-GShare-like.
 
 Run:  PYTHONPATH=src python examples/serve_autoscale.py
 """
 import time
 
+import jax
 import numpy as np
 
 from repro.configs import ARCHS, reduced
@@ -22,7 +24,8 @@ from repro.serving import Gateway, InferenceRequest, PodEngine
 from repro.workloads import standard_workload
 
 # ---------------------------------------------------------------- part 1
-print("=== live serving (reduced qwen2.5, CPU) ===")
+print(f"=== live serving (reduced qwen2.5 on {jax.devices()[0].device_kind})"
+      " ===")
 cfg = reduced(ARCHS["qwen2.5-3b"])
 vgpu = VirtualGPU("GPU-demo", window_ms=50.0)
 sched = HASGPUScheduler()

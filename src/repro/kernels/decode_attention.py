@@ -6,7 +6,9 @@ fill state), and maintains the online-softmax carry for all G query heads
 of the kv head at once — the (G, hd) query tile is small and stays
 resident. This is the memory-bound kernel of batched decode: arithmetic
 intensity ~= G, so block_k is chosen large (512) to stream the cache at
-full HBM bandwidth.
+full HBM bandwidth. The mask travels as an int32 ``(1, T)`` row blocked
+``(1, block_k)``: a 1-D block of it does not match the layout XLA gives
+the mask once T exceeds 512.
 """
 from __future__ import annotations
 
@@ -33,9 +35,9 @@ def _dec_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_sc, l_sc, acc_sc,
     q = q_ref[0].astype(jnp.float32) * scale          # (G, hd)
     k = k_ref[0].astype(jnp.float32)                  # (bk, hd)
     v = v_ref[0].astype(jnp.float32)
-    ok = valid_ref[...]                               # (bk,)
+    ok = valid_ref[...] != 0                          # (1, bk)
     s = q @ k.T                                       # (G, bk)
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_sc[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -66,6 +68,7 @@ def decode_attention(q, k, v, valid, *, block_k=512, interpret=None):
     qf = q.reshape(B, K, G, hd).reshape(B * K, G, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(B * K, T, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * K, T, hd)
+    mask = valid.astype(jnp.int32).reshape(1, T)
 
     out = pl.pallas_call(
         functools.partial(_dec_kernel, scale=scale, n_k=n_k),
@@ -74,7 +77,7 @@ def decode_attention(q, k, v, valid, *, block_k=512, interpret=None):
             pl.BlockSpec((1, G, hd), lambda bh, j: (bh, 0, 0)),
             pl.BlockSpec((1, block_k, hd), lambda bh, j: (bh, j, 0)),
             pl.BlockSpec((1, block_k, hd), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((block_k,), lambda bh, j: (j,)),
+            pl.BlockSpec((1, block_k), lambda bh, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, G, hd), lambda bh, j: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * K, G, hd), q.dtype),
@@ -84,5 +87,5 @@ def decode_attention(q, k, v, valid, *, block_k=512, interpret=None):
             pltpu.VMEM((G, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qf, kf, vf, valid)
+    )(qf, kf, vf, mask)
     return out.reshape(B, 1, K, G, hd)

@@ -32,16 +32,31 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc_sc, *, n_k):
         o_ref[0] = acc_sc[...].astype(o_ref.dtype)
 
 
+def _fit(dim: int, pref: int, align: int) -> int:
+    """Largest block <= ``pref`` that divides ``dim`` and is a multiple of
+    ``align``; the whole dimension when none is (always a legal block).
+    E.g. deepseek-moe's K=1408=11*128 takes 128 where 512 would not tile."""
+    if dim <= pref:
+        return dim
+    for b in range(pref - pref % align, 0, -align):
+        if dim % b == 0:
+            return b
+    return dim
+
+
 @functools.partial(jax.jit, static_argnames=("block_c", "block_n", "block_k",
                                              "interpret"))
 def gmm(x, w, *, block_c=128, block_n=128, block_k=512, interpret=None):
-    """x: (E, C, K) @ w: (E, K, N) -> (E, C, N)."""
+    """x: (E, C, K) @ w: (E, K, N) -> (E, C, N).
+
+    Blocks are the preferred sizes shrunk to divisors of the dimensions
+    that obey the TPU tiling: multiples of 8 for C, of 128 for N and K."""
     E, C, K = x.shape
     N = w.shape[-1]
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    bc, bn, bk = min(block_c, C), min(block_n, N), min(block_k, K)
-    assert C % bc == 0 and N % bn == 0 and K % bk == 0
+    bc, bn, bk = _fit(C, block_c, 8), _fit(N, block_n, 128), \
+        _fit(K, block_k, 128)
     grid = (E, C // bc, N // bn, K // bk)
     return pl.pallas_call(
         functools.partial(_gmm_kernel, n_k=K // bk),

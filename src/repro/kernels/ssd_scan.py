@@ -7,6 +7,11 @@ intra-chunk dual form — (Q,Q) decay-masked C.B^T scores feeding the MXU —
 plus the carried-state contribution, then advances the state. The (Q,Q)
 working set is what the chunk size tunes against VMEM (Q=256 default:
 256x256 f32 = 256 KiB).
+
+Mosaic has no cumsum, so the wrapper takes the in-chunk cumulative sum
+of dA in XLA and hands the kernel the per-step vectors as ``(Q, 1)``
+columns (dt, cum) and a ``(1, Q)`` row (cum). Each block then spans its
+array's last two dims, as the TPU tiling requires for any chunk count.
 """
 from __future__ import annotations
 
@@ -18,8 +23,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, da_ref, h0_ref, y_ref,
-                hout_ref, h_sc, *, Q, n_chunks):
+def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, cumr_ref, h0_ref,
+                y_ref, hout_ref, h_sc, *, Q, n_chunks):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -29,23 +34,23 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, da_ref, h0_ref, y_ref,
     x = x_ref[0, 0].astype(jnp.float32)               # (Q, hd)
     Bm = b_ref[0, 0].astype(jnp.float32)              # (Q, N)
     Cm = c_ref[0, 0].astype(jnp.float32)              # (Q, N)
-    dt = dt_ref[0, 0].astype(jnp.float32)             # (Q,)
-    dA = da_ref[0, 0].astype(jnp.float32)             # (Q,)
+    dt = dt_ref[0, 0]                                 # (Q, 1)
+    li = cum_ref[0, 0]                                # (Q, 1)
+    lj = cumr_ref[0, 0]                               # (1, Q)
+    # cum[Q-1] by a masked reduction: a lane slice at offset Q-1 has a
+    # layout Mosaic cannot broadcast from
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1) == Q - 1
+    total = jnp.sum(jnp.where(last, lj, 0.0), axis=1, keepdims=True)  # (1,1)
 
-    cum = jnp.cumsum(dA)                              # (Q,)
-    total = cum[-1]
     cb = Cm @ Bm.T                                    # (Q, Q)
-    li = cum[:, None]
-    lj = cum[None, :]
     tri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     decay = jnp.exp(jnp.where(tri, li - lj, -1e30))
-    scores = cb * decay * dt[None, :]
-    y_intra = scores @ x                              # (Q, hd)
+    y_intra = (cb * decay) @ (x * dt)                 # (Q, hd)
     h = h_sc[...]                                     # (hd, N)
-    y_inter = (Cm * jnp.exp(cum)[:, None]) @ h.T      # (Q, hd)
-    w = dt * jnp.exp(total - cum)                     # (Q,)
-    dstate = (x * w[:, None]).T @ Bm                  # (hd, N)
+    y_inter = (Cm * jnp.exp(li)) @ h.T                # (Q, hd)
+    w = dt * jnp.exp(total - li)                      # (Q, 1)
+    dstate = (x * w).T @ Bm                           # (hd, N)
     h_new = jnp.exp(total) * h + dstate
     h_sc[...] = h_new
     y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
@@ -68,23 +73,28 @@ def ssd_chunk_scan(xc, Bc, Cc, dtc, dAc, h0, *, interpret=None):
     xf = xc.transpose(1, 3, 0, 2, 4).reshape(BH, nc, Q, hd)
     bf = Bc.transpose(1, 3, 0, 2, 4).reshape(BH, nc, Q, N)
     cf = Cc.transpose(1, 3, 0, 2, 4).reshape(BH, nc, Q, N)
-    dtf = dtc.transpose(1, 3, 0, 2).reshape(BH, nc, Q)
-    daf = dAc.transpose(1, 3, 0, 2).reshape(BH, nc, Q)
+    dtf = dtc.astype(jnp.float32).transpose(1, 3, 0, 2).reshape(BH, nc, Q)
+    cum = jnp.cumsum(dAc.astype(jnp.float32), axis=2)
+    cumf = cum.transpose(1, 3, 0, 2).reshape(BH, nc, Q)
     h0f = h0.reshape(BH, hd, N)
+
+    def per_chunk(last2):
+        return pl.BlockSpec((1, 1) + last2, lambda bh, j: (bh, j, 0, 0))
 
     y, hout = pl.pallas_call(
         functools.partial(_ssd_kernel, Q=Q, n_chunks=nc),
         grid=(BH, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, Q, hd), lambda bh, j: (bh, j, 0, 0)),
-            pl.BlockSpec((1, 1, Q, N), lambda bh, j: (bh, j, 0, 0)),
-            pl.BlockSpec((1, 1, Q, N), lambda bh, j: (bh, j, 0, 0)),
-            pl.BlockSpec((1, 1, Q), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((1, 1, Q), lambda bh, j: (bh, j, 0)),
+            per_chunk((Q, hd)),
+            per_chunk((Q, N)),
+            per_chunk((Q, N)),
+            per_chunk((Q, 1)),
+            per_chunk((Q, 1)),
+            per_chunk((1, Q)),
             pl.BlockSpec((1, hd, N), lambda bh, j: (bh, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, Q, hd), lambda bh, j: (bh, j, 0, 0)),
+            per_chunk((Q, hd)),
             pl.BlockSpec((1, hd, N), lambda bh, j: (bh, 0, 0)),
         ],
         out_shape=[
@@ -93,7 +103,7 @@ def ssd_chunk_scan(xc, Bc, Cc, dtc, dAc, h0, *, interpret=None):
         ],
         scratch_shapes=[pltpu.VMEM((hd, N), jnp.float32)],
         interpret=interpret,
-    )(xf, bf, cf, dtf, daf, h0f)
+    )(xf, bf, cf, dtf[..., None], cumf[..., None], cumf[:, :, None, :], h0f)
 
     y = y.reshape(B, nh, nc, Q, hd).transpose(2, 0, 3, 1, 4)
     return hout.reshape(B, nh, hd, N), y

@@ -56,8 +56,6 @@ def run_combo(arch: str, shape: str, multi_pod: bool, verbose=True):
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 returns [dict]
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     analysis = hlo_analysis.analyze(hlo_text, case.scan_trip_hints)
     terms = roofline_terms(analysis, n_chips)

@@ -1,33 +1,20 @@
 """Production mesh construction.
 
-Defined as functions (not module-level constants) so importing this module
-never touches jax device state — required because the dry-run must set
-XLA_FLAGS before any jax initialization.
+Defined as a function (not a module-level constant) so importing this
+module never touches jax device state — required because the dry-run must
+set XLA_FLAGS before any jax initialization.
 """
 from __future__ import annotations
 
 import jax
 
 
-def mesh_kwargs(n_axes: int) -> dict:
-    """`axis_types=` for jax.make_mesh where supported (jax >= 0.5 added
-    jax.sharding.AxisType; older versions default to Auto implicitly)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_kwargs(len(axes)))
-
-
-def make_host_mesh():
-    """Degenerate 1x1 mesh for CPU smoke runs of the same launch code."""
-    return jax.make_mesh((1, 1), ("data", "model"), **mesh_kwargs(2))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def axis_sizes(mesh) -> dict:

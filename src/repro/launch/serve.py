@@ -1,9 +1,13 @@
 """Serving launcher: deploy a function under HAS-GPU control and replay a
-workload through the real engine (CPU: reduced config) or lower the
-serving steps against the production mesh (--dry-run).
+workload through the real engine, or lower the serving steps against the
+production mesh (--dry-run).
+
+The named config is served as published, on the default device.
+``--reduced`` serves its small same-family variant instead, for a CPU
+rehearsal with ``JAX_PLATFORMS=cpu``.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b \
-      --requests 16 [--sm 4 --quota 0.5 --batch 4] [--dry-run]
+      --requests 16 [--sm 4 --quota 0.5 --batch 4] [--reduced] [--dry-run]
 """
 import argparse
 import os
@@ -19,6 +23,8 @@ def main():
     ap.add_argument("--quota", type=float, default=0.5)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--new-tokens", type=int, default=8)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the reduced config (CPU rehearsal)")
     ap.add_argument("--dry-run", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
@@ -31,14 +37,20 @@ def main():
         run_combo(args.arch, args.shape, multi_pod=args.multi_pod)
         return
 
+    import jax
     import numpy as np
-    from repro.configs import ARCHS, reduced
+    from repro.configs import get_config, reduced
     from repro.core.scheduler import HASGPUScheduler
     from repro.core.vgpu import PodAlloc, VirtualGPU
+    from repro.launch import compile_cache
     from repro.serving import Gateway, InferenceRequest, PodEngine
 
-    cfg = reduced(ARCHS[args.arch])
-    print(f"[serve] reduced {cfg.name} on CPU, pod sm={args.sm} "
+    compile_cache.enable()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduced(cfg)
+    print(f"[serve] {'reduced ' if args.reduced else ''}{cfg.name} on "
+          f"{jax.devices()[0].device_kind}, pod sm={args.sm} "
           f"q={args.quota} batch={args.batch}")
     vgpu = VirtualGPU("GPU-0", window_ms=50.0)
     sched = HASGPUScheduler()

@@ -1,9 +1,9 @@
 """Training launcher.
 
-On a real TPU slice this binary is what every host runs (jax.distributed
-initializes from the TPU environment); on CPU it runs the same code on a
-host mesh. The dry-run path (--dry-run) lowers against the production
-mesh without executing.
+Trains the named config as published on the default device; ``--reduced``
+trains the small same-family variant instead (for CPU rehearsal, with
+``JAX_PLATFORMS=cpu``). The dry-run path (--dry-run) lowers against the
+production mesh without executing.
 
   PYTHONPATH=src python -m repro.launch.train --arch olmo-1b \
       --shape train_4k --steps 100 [--dry-run] [--ckpt path.npz]
@@ -46,10 +46,11 @@ def main():
                                 optimizer as opt_mod, steps)
 
     cfg = get_config(args.arch)
-    if args.reduced or jax.default_backend() == "cpu":
+    if args.reduced:
         cfg = reduced(cfg)
-        print(f"[train] CPU backend: using reduced {cfg.name} "
-              f"({cfg.param_count()/1e6:.1f}M params)")
+    print(f"[train] {'reduced ' if args.reduced else ''}{cfg.name} "
+          f"({cfg.param_count()/1e6:.1f}M params) on "
+          f"{jax.devices()[0].device_kind}")
     adamw = opt_mod.AdamWConfig(lr=args.lr, warmup_steps=args.steps // 10,
                                 total_steps=args.steps)
     train_step = jax.jit(steps.make_train_step(cfg, adamw,

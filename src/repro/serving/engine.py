@@ -2,9 +2,11 @@
 
 One ``PodEngine`` is a function instance: jitted prefill + decode steps
 for its architecture, a batcher, and a libhas shim that acquires time
-tokens sized by the pod's (sm, quota) before every dispatch. The CPU demo
-uses reduced configs; the dispatch path (batch -> prefill -> n x decode)
-is the production one.
+tokens sized by the pod's (sm, quota) before every dispatch. The engine
+holds one whole model on the default device: ``chip_smoke.py`` serves
+qwen2.5-3b at its published widths on one TPU v5e, and CPU tests serve
+``reduced()`` configs through the same dispatch path (batch -> prefill
+-> n x decode).
 """
 from __future__ import annotations
 

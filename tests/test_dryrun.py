@@ -44,8 +44,6 @@ def test_analyzer_scan_equals_unroll():
 
 
 def test_analyzer_collectives():
-    from repro.launch.mesh import mesh_kwargs
-    mesh = jax.make_mesh((1,), ("x",), **mesh_kwargs(1))
     # single-device: no collectives expected
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     a = ha.analyze(jax.jit(lambda t: t @ t).lower(x).compile().as_text())

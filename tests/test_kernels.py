@@ -41,6 +41,7 @@ def test_flash_attention(B, S, T, K, G, hd, dtype, causal, window):
     (2, 128, 2, 2, 64, 100),
     (1, 256, 1, 8, 128, 10),
     (4, 64, 4, 1, 64, 63),
+    (1, 1024, 2, 8, 128, 700),   # qwen2.5-3b widths at T=1024, many blocks
 ])
 def test_decode_attention(B, T, K, G, hd, pos):
     q = arr(B, 1, K, G, hd)
@@ -79,6 +80,19 @@ def test_gmm(E, C, K, N, dtype):
     out = ops.gmm(x, w, block_c=32, block_n=32, block_k=64)
     want = ref.gmm_ref(x, w)
     assert rel_err(out, want) < (0.02 if dtype == jnp.bfloat16 else 1e-5)
+
+
+def test_gmm_default_blocks_tile_unaligned_k():
+    """deepseek-moe's expert width K=1408 takes 128-wide K blocks (512
+    does not divide it) and still matches the oracle."""
+    from repro.kernels.moe_gmm import _fit
+    assert _fit(1408, 512, 128) == 128
+    assert _fit(2048, 512, 128) == 512
+    assert _fit(96, 32, 128) == 96      # no aligned divisor: whole dim
+    assert _fit(200, 128, 8) == 40
+    x = arr(2, 16, 1408, dtype=jnp.float32)
+    w = arr(2, 1408, 256, dtype=jnp.float32, scale=1408 ** -0.5)
+    assert rel_err(ops.gmm(x, w), ref.gmm_ref(x, w)) < 1e-5
 
 
 def test_expert_ffn():
