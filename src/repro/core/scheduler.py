@@ -96,15 +96,18 @@ class GPUClient:
         self.pod_id = pod_id
         self._lock = threading.Lock()
 
-    def acquire(self, cost_s: float) -> None:
+    def acquire(self, cost_s: float) -> float:
         """Real-time acquire: sleeps until the pod's token share allows a
-        task of cost_s seconds (the libhas handshake)."""
+        task of cost_s seconds (the libhas handshake). Returns the
+        seconds it slept, 0.0 where the share allowed the task at once."""
         with self._lock:
             now = time.monotonic()
             done_at = self.ledger.acquire(self.pod_id, cost_s, now)
             wait = done_at - now - cost_s
-            if wait > 0:
-                time.sleep(wait)
+            if wait <= 0:
+                return 0.0
+            time.sleep(wait)
+            return wait
 
 
 class HASGPUScheduler:

@@ -13,6 +13,22 @@ _req_ids = itertools.count()
 
 
 @dataclasses.dataclass
+class BatchRecord:
+    """What ``PodEngine.step`` measured of one served batch; every request
+    of the batch holds the same record. Times are host ``time.monotonic``
+    seconds, the clock of ``InferenceRequest.arrival``."""
+    batch_id: int                   # the engine's count of its batches
+    steps: int                      # decode steps: the longest output
+    started: float                  # the batch left the queue
+    ended: Optional[float] = None   # its outputs were stamped
+    slept_s: float = 0.0            # slept in the libhas acquires
+    # host time the device waited on for each token, summed over the
+    # decode steps: from the host copy of the previous token to the
+    # return of the next decode launch, less that launch's sleep
+    turnaround_s: float = 0.0
+
+
+@dataclasses.dataclass
 class InferenceRequest:
     prompt: np.ndarray              # (prompt_len,) int32
     max_new_tokens: int = 16
@@ -20,6 +36,7 @@ class InferenceRequest:
     arrival: float = dataclasses.field(default_factory=time.monotonic)
     output: Optional[np.ndarray] = None
     completed_at: Optional[float] = None
+    batch_record: Optional[BatchRecord] = None   # set once served
 
     @property
     def latency(self) -> Optional[float]:

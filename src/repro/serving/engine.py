@@ -7,13 +7,22 @@ holds one whole model on the default device: ``chip_smoke.py`` serves
 qwen2.5-3b at its published widths on one TPU v5e, and CPU tests serve
 ``reduced()`` configs through the same dispatch path (batch -> prefill
 -> n x decode).
+
+``step`` runs inside ``jax.profiler.TraceAnnotation`` spans, which cost
+about a microsecond each when no profiler is recording: ``engine.batch``
+(with the batch id, rows, decode steps and request ids) around one
+batch, and inside it ``engine.prefill`` and ``engine.decode`` around
+each launch, ``engine.sample`` around each eager argmax and
+``engine.sync`` around each host copy of a token; ``LibHas.launch``
+adds ``libhas.acquire``. Each served request holds the batch's
+``BatchRecord``.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
+import itertools
 import time
-from typing import List, Optional
+from typing import List
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +35,7 @@ from repro.core.perf_model import FnSpec, exec_time
 from repro.core.scheduler import HASGPUScheduler
 from repro.core.vgpu import PodAlloc, VirtualGPU
 from repro.models import CallOpts
-from repro.serving.batcher import Batcher, InferenceRequest
+from repro.serving.batcher import Batcher, BatchRecord, InferenceRequest
 from repro.serving.libhas import LibHas
 from repro.training import steps
 
@@ -38,9 +47,18 @@ def compiled_steps(cfg: ArchConfig, max_seq: int, opts: CallOpts) -> tuple:
     Pods of the same function differ only in (sm, quota, batch) — none
     of which affect compilation — so every engine of a fn shares one
     jit cache instead of re-tracing per pod (the profiling harness
-    sweeps many (sm, quota) points per arch and rides on this too)."""
+    sweeps many (sm, quota) points per arch and rides on this too).
+
+    The step functions' names are read by traces: their programs are
+    ``jit_prefill_step`` and ``jit_decode_step``."""
     return (jax.jit(steps.make_prefill_step(cfg, max_seq, opts)),
             jax.jit(steps.make_decode_step(cfg, opts)))
+
+
+def _sample(logits) -> jax.Array:
+    """Greedy next token of each row, ``(B, 1) int32``, on the device."""
+    with jax.profiler.TraceAnnotation("engine.sample"):
+        return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
 
 
 class PodEngine:
@@ -60,7 +78,7 @@ class PodEngine:
         self.libhas = LibHas(client=client)
         self.batcher = Batcher(max_batch=pod.batch, pad_id=pad_id)
         self._prefill, self._decode = compiled_steps(cfg, max_seq, opts)
-        self.completed: List[InferenceRequest] = []
+        self._batch_ids = itertools.count()
 
     # cost of one dispatch in *owned accelerator seconds* for this pod,
     # on the chip actually hosting it — charging at reference-device
@@ -86,32 +104,49 @@ class PodEngine:
         self.batcher.submit(req)
 
     def step(self) -> List[InferenceRequest]:
-        """Serve one batch if ready. Returns completed requests."""
+        """Serve one batch if ready. Returns completed requests, each
+        holding the batch's ``BatchRecord``."""
         if not self.batcher.ready():
             return []
+        started = time.monotonic()
         reqs = self.batcher.next_batch()
-        prompts = self.batcher.pad_prompts(reqs, pad_id=self.batcher.pad_id,
-                                           pad_to=None)
-        B, L = prompts.shape
-        v = self.cfg.num_visual_tokens or 0
-        batch = {"tokens": jnp.asarray(prompts), **self._extra_inputs(B)}
-        logits, cache = self.libhas.launch(
-            self._prefill, self.params, batch, cost_s=self._cost(B * L))
-        n_new = max(r.max_new_tokens for r in reqs)
-        outs = np.zeros((B, n_new), np.int32)
-        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-        for i in range(n_new):
-            outs[:, i] = np.asarray(tok[:, 0])
-            pos = jnp.asarray(v + L + i, jnp.int32)
-            logits, cache = self.libhas.launch(
-                self._decode, self.params, tok, pos, cache,
-                cost_s=self._cost(B))
-            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-        now = time.monotonic()
-        for j, r in enumerate(reqs):
-            r.output = outs[j, :r.max_new_tokens]
-            r.completed_at = now
-        self.completed.extend(reqs)
+        rec = BatchRecord(batch_id=next(self._batch_ids),
+                          steps=max(r.max_new_tokens for r in reqs),
+                          started=started)
+        libhas = self.libhas
+        slept0 = libhas.slept_s
+        with jax.profiler.TraceAnnotation(
+                "engine.batch", batch=rec.batch_id, rows=len(reqs),
+                steps=rec.steps, reqs=" ".join(str(r.req_id) for r in reqs)):
+            prompts = self.batcher.pad_prompts(
+                reqs, pad_id=self.batcher.pad_id, pad_to=None)
+            B, L = prompts.shape
+            v = self.cfg.num_visual_tokens or 0
+            batch = {"tokens": jnp.asarray(prompts), **self._extra_inputs(B)}
+            with jax.profiler.TraceAnnotation("engine.prefill"):
+                logits, cache = libhas.launch(
+                    self._prefill, self.params, batch,
+                    cost_s=self._cost(B * L))
+            tok = _sample(logits)
+            outs = np.zeros((B, rec.steps), np.int32)
+            for i in range(rec.steps):
+                with jax.profiler.TraceAnnotation("engine.sync"):
+                    outs[:, i] = np.asarray(tok[:, 0])
+                synced, slept = time.monotonic(), libhas.slept_s
+                pos = jnp.asarray(v + L + i, jnp.int32)
+                with jax.profiler.TraceAnnotation("engine.decode"):
+                    logits, cache = libhas.launch(
+                        self._decode, self.params, tok, pos, cache,
+                        cost_s=self._cost(B))
+                rec.turnaround_s += (time.monotonic() - synced
+                                     - (libhas.slept_s - slept))
+                tok = _sample(logits)
+            now = time.monotonic()
+            rec.ended, rec.slept_s = now, libhas.slept_s - slept0
+            for j, r in enumerate(reqs):
+                r.output = outs[j, :r.max_new_tokens]
+                r.completed_at = now
+                r.batch_record = rec
         return reqs
 
     def set_quota(self, vgpu: VirtualGPU, quota: float) -> None:

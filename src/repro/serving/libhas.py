@@ -7,11 +7,18 @@ dispatch boundary: the engine wraps every step call in
 ``LibHas.launch(...)``, which (a) acquires time tokens from the pod's GPU
 client and (b) enforces the pod's HBM budget against the compiled step's
 memory analysis.
+
+Operator reading: ``tokens_acquired_s`` (seconds charged) against
+``slept_s`` (seconds the acquires slept) against the device time of the
+launched steps says how far the charge, and not the chip, paces the pod.
+Each acquire runs inside a ``libhas.acquire`` profiler span.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Callable, Optional
+
+import jax
 
 from repro.core.scheduler import GPUClient
 
@@ -26,7 +33,8 @@ class LibHas:
     hbm_budget_bytes: Optional[int] = None
     cost_estimator: Optional[Callable[..., float]] = None
     launches: int = 0
-    tokens_acquired_s: float = 0.0
+    tokens_acquired_s: float = 0.0   # seconds charged
+    slept_s: float = 0.0             # seconds the acquires slept
 
     def check_memory(self, compiled) -> None:
         """cuMemAlloc-interception analogue: reject steps whose compiled
@@ -49,7 +57,8 @@ class LibHas:
         if cost_s is None and self.cost_estimator is not None:
             cost_s = self.cost_estimator(*args, **kw)
         if cost_s is not None:
-            self.client.acquire(cost_s)
+            with jax.profiler.TraceAnnotation("libhas.acquire"):
+                self.slept_s += self.client.acquire(cost_s) or 0.0
             self.tokens_acquired_s += cost_s
         self.launches += 1
         return fn(*args, **kw)
