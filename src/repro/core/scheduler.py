@@ -27,6 +27,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from repro.configs.gpus import GPUType
 from repro.core.vgpu import PodAlloc, VirtualGPU
 
+# A ledger completion this close past the clock is rounding, not pacing:
+# the window walk sums a charge back to its end only to float precision.
+_MIN_SLEEP_S = 1e-6
+
 
 class TokenLedger:
     """Window-based token accounting for one vGPU partition set.
@@ -97,14 +101,18 @@ class GPUClient:
         self._lock = threading.Lock()
 
     def acquire(self, cost_s: float) -> float:
-        """Real-time acquire: sleeps until the pod's token share allows a
-        task of cost_s seconds (the libhas handshake). Returns the
-        seconds it slept, 0.0 where the share allowed the task at once."""
+        """Real-time acquire (the libhas handshake): pays for ``cost_s``
+        seconds the pod has just held the device. The charge is booked
+        where it happened, as ``[now - cost_s, now]`` on the pod's
+        ledger, and the call sleeps until the ledger's completion time,
+        so the pod is paced to its quota of wall time; while its share
+        covers the charge (at quota 1.0, any run of disjoint intervals)
+        it does not sleep. Returns the seconds it slept."""
         with self._lock:
             now = time.monotonic()
-            done_at = self.ledger.acquire(self.pod_id, cost_s, now)
-            wait = done_at - now - cost_s
-            if wait <= 0:
+            done_at = self.ledger.acquire(self.pod_id, cost_s, now - cost_s)
+            wait = done_at - now
+            if wait <= _MIN_SLEEP_S:
                 return 0.0
             time.sleep(wait)
             return wait

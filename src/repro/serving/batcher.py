@@ -22,6 +22,7 @@ class BatchRecord:
     started: float                  # the batch left the queue
     ended: Optional[float] = None   # its outputs were stamped
     slept_s: float = 0.0            # slept in the libhas acquires
+    charged_s: float = 0.0          # device seconds the acquires paid for
     # host time the device waited on for each token, summed over the
     # decode steps: from the host copy of the previous token to the
     # return of the next decode launch, less that launch's sleep

@@ -1,8 +1,12 @@
 """Serving engine: real JAX prefill/decode under HAS resource control.
 
 One ``PodEngine`` is a function instance: jitted prefill + decode steps
-for its architecture, a batcher, and a libhas shim that acquires time
-tokens sized by the pod's (sm, quota) before every dispatch. The engine
+for its architecture, a batcher, and a libhas shim through which every
+dispatch pays for device time. What is paid is measured, not predicted:
+each launch's seconds from its dispatch to the return of the host sync
+that brings its token, charged at the next launch (the batch's last
+launch once the batch ends); the pod's quota of each time-token window
+then decides whether the charge sleeps. The engine
 holds one whole model on the default device: ``chip_smoke.py`` serves
 qwen2.5-3b at its published widths on one TPU v5e, and CPU tests serve
 ``reduced()`` configs through the same dispatch path (batch -> prefill
@@ -13,9 +17,9 @@ about a microsecond each when no profiler is recording: ``engine.batch``
 (with the batch id, rows, decode steps and request ids) around one
 batch, and inside it ``engine.prefill`` and ``engine.decode`` around
 each launch, ``engine.sample`` around each eager argmax and
-``engine.sync`` around each host copy of a token; ``LibHas.launch``
-adds ``libhas.acquire``. Each served request holds the batch's
-``BatchRecord``.
+``engine.sync`` around each host copy of a token and the wait for the
+last decode; each libhas charge adds ``libhas.acquire``. Each served
+request holds the batch's ``BatchRecord``.
 """
 from __future__ import annotations
 
@@ -80,9 +84,10 @@ class PodEngine:
         self._prefill, self._decode = compiled_steps(cfg, max_seq, opts)
         self._batch_ids = itertools.count()
 
-    # cost of one dispatch in *owned accelerator seconds* for this pod,
-    # on the chip actually hosting it — charging at reference-device
-    # physics would over-token fast chips and under-token slow ones
+    # the perf model's cost of one dispatch in *owned accelerator
+    # seconds* for this pod, on the chip actually hosting it, for callers
+    # that charge before a launch (the profiling harness); ``step``
+    # charges measured time instead
     def _cost(self, n_tokens_equiv: int) -> float:
         gpu = self.pod.gpu_type or DEFAULT_GPU_TYPE
         t_full = exec_time(self.spec, max(self.pod.batch, 1), self.pod.sm,
@@ -114,7 +119,7 @@ class PodEngine:
                           steps=max(r.max_new_tokens for r in reqs),
                           started=started)
         libhas = self.libhas
-        slept0 = libhas.slept_s
+        slept0, charged0 = libhas.slept_s, libhas.tokens_acquired_s
         with jax.profiler.TraceAnnotation(
                 "engine.batch", batch=rec.batch_id, rows=len(reqs),
                 steps=rec.steps, reqs=" ".join(str(r.req_id) for r in reqs)):
@@ -123,26 +128,35 @@ class PodEngine:
             B, L = prompts.shape
             v = self.cfg.num_visual_tokens or 0
             batch = {"tokens": jnp.asarray(prompts), **self._extra_inputs(B)}
+            # Each launch is charged what it held the device, from its
+            # dispatch to the return of the sync that brings its token
+            # (prefill: sync 0; decode i: sync i + 1), and pays at the next
+            # launch. The last decode's token is never read: it is waited
+            # for, and paid, once the loop ends.
             with jax.profiler.TraceAnnotation("engine.prefill"):
                 logits, cache = libhas.launch(
-                    self._prefill, self.params, batch,
-                    cost_s=self._cost(B * L))
+                    self._prefill, self.params, batch, cost_s=0.0)
             tok = _sample(logits)
             outs = np.zeros((B, rec.steps), np.int32)
             for i in range(rec.steps):
                 with jax.profiler.TraceAnnotation("engine.sync"):
                     outs[:, i] = np.asarray(tok[:, 0])
                 synced, slept = time.monotonic(), libhas.slept_s
+                busy = synced - libhas.dispatched_at
                 pos = jnp.asarray(v + L + i, jnp.int32)
                 with jax.profiler.TraceAnnotation("engine.decode"):
                     logits, cache = libhas.launch(
                         self._decode, self.params, tok, pos, cache,
-                        cost_s=self._cost(B))
+                        cost_s=busy)
                 rec.turnaround_s += (time.monotonic() - synced
                                      - (libhas.slept_s - slept))
                 tok = _sample(logits)
+            with jax.profiler.TraceAnnotation("engine.sync"):
+                tok.block_until_ready()
+            libhas.charge(time.monotonic() - libhas.dispatched_at)
             now = time.monotonic()
             rec.ended, rec.slept_s = now, libhas.slept_s - slept0
+            rec.charged_s = libhas.tokens_acquired_s - charged0
             for j, r in enumerate(reqs):
                 r.output = outs[j, :r.max_new_tokens]
                 r.completed_at = now
