@@ -4,6 +4,7 @@ SwiGLU feed-forward per layer, tied or untied embeddings.
 Everything the benchmark needs to know about this family lives here:
 
 - ``arch_config``: the program's ``ArchConfig`` for a configuration file;
+- ``tiny``: a tiny configuration of the same kind, for the CPU tests;
 - ``make_weights``: seeded weights, made on the device in one program, in a
   layout of the benchmark's own, and ``program_params``, the same arrays
   re-nested the way ``PodEngine`` takes them (no copy);
@@ -62,15 +63,33 @@ class Shape:
 
 
 def arch_config(conf: dict):
-    """The program's ``ArchConfig`` for this file, as published."""
-    from repro.configs.base import ArchConfig
+    """The program's ``ArchConfig`` for this file, as published; the
+    file's norm eps goes in where ``ArchConfig`` has a ``norm_eps``."""
+    from repro.configs import base
     s = Shape.of(conf)
-    return ArchConfig(
+    extra = {}
+    if "norm_eps" in {f.name for f in dataclasses.fields(base.ArchConfig)}:
+        extra["norm_eps"] = s.norm_eps
+    return base.ArchConfig(
         name=conf["name"], family="dense", source=conf["source"],
         num_layers=s.layers, d_model=s.d_model, num_heads=s.heads,
         num_kv_heads=s.kv_heads, head_dim=s.head_dim, d_ff=s.d_ff,
         vocab_size=s.vocab, qkv_bias=s.qkv_bias, norm=s.norm, act="silu",
-        rope_theta=s.rope_theta, tie_embeddings=s.tied, dtype="bfloat16")
+        rope_theta=s.rope_theta, tie_embeddings=s.tied, dtype="bfloat16",
+        **extra)
+
+
+TINY = {"name": "tiny", "source": "test", "family": "dense_decoder",
+        "norm": "rmsnorm", "qkv_bias": True, "hidden_size": 128,
+        "intermediate_size": 256, "num_hidden_layers": 4,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "vocab_size": 512, "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
+        "tie_word_embeddings": True}
+
+
+def tiny(conf: dict) -> dict:
+    """A tiny configuration with ``conf``'s norm and biases."""
+    return dict(TINY, norm=conf["norm"], qkv_bias=conf["qkv_bias"])
 
 
 # ------------------------------------------------------------ weights

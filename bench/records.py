@@ -11,6 +11,7 @@ left out of the line.
 from __future__ import annotations
 
 import math
+from collections import Counter
 from typing import List, Optional
 
 
@@ -38,6 +39,18 @@ def turnaround_ms(run) -> Optional[float]:
     if not steps:
         return None
     return 1e3 * sum(r.turnaround_s for r in recs) / steps
+
+
+def batch_fill(run) -> Optional[float]:
+    """Mean rows per batch started in the window over the pod's batch,
+    in %; a batch's rows are the attempted requests stamped with its
+    record."""
+    recs = batch_records(run)
+    if recs is None:
+        return None
+    rows = Counter(id(r.request.batch_record) for r in run.attempted)
+    return (100.0 * sum(rows[id(rec)] for rec in recs)
+            / (len(recs) * run.cell.traffic["pod"]["batch"]))
 
 
 def admit_waits_ms(run) -> Optional[List[float]]:

@@ -136,7 +136,8 @@ def run(args: argparse.Namespace, cell: Optional[spec_mod.Cell] = None,
         t_trace=w["t_trace"])
     if traced:
         tb = run_.traced_batches
-        print(f"bench: traced {len(tb)} batches; programs in the trace: "
+        print(f"bench: traced from {w['t_trace'] - w['t_window']:.2f} s "
+              f"of the window, {len(tb)} batches; programs in the trace: "
               f"{len(trace_mod.programs(run_.trace, 'prefill_step'))} "
               f"prefill (want {len(tb)}), "
               f"{len(trace_mod.programs(run_.trace, 'decode_step'))} decode "

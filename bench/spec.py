@@ -4,7 +4,11 @@ A cell (a ``workloads`` entry) names a configuration and a traffic mix.
 Its files are found by name:
 
 - ``bench/configs/<config>.json``: the configuration as it is run, with the
-  family of its architecture (``bench/families/<family>.py``);
+  family of its architecture (``bench/families/<family>.py``), which gives
+  ``Shape`` (``Shape.of(config)``, with ``vocab``), ``arch_config``,
+  ``make_weights``, ``program_params``, ``served_gaps``, ``logit_gaps``,
+  ``decode_cost``, ``request_flops`` and ``tiny``
+  (``bench/families/dense_decoder.py`` says what each is);
 - ``bench/traffic/<traffic>.json``: the mix that ``bench/traffic.py``
   generates, and the pod that serves it;
 - ``bench/cells/<cell>.json``: the output check (sample size, limit);

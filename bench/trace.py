@@ -7,9 +7,10 @@ work on those alone, so that they can be tested on a synthetic trace:
 - device programs (the ``XLA Modules`` line of the first device plane),
   found by a substring of their name (``prefill_step``, ``decode_step``);
 - device operations (the ``XLA Ops`` line), whose union is the busy time;
-- the benchmark's own host spans (``bench.*`` ``TraceAnnotation``s), which
-  group programs by the pump that ran them and say what the host was
-  doing in each idle gap of the device.
+- host spans: the benchmark's own (``bench.*`` ``TraceAnnotation``s),
+  which group programs by the pump that ran them, and the program's
+  (``engine.*``, ``libhas.acquire``); the innermost span open in an idle
+  gap of the device says what the host was doing in it.
 
 All times are nanoseconds on the trace's clock.
 """
@@ -23,6 +24,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 Event = Tuple[str, float, float]   # (name, start_ns, end_ns)
 
 SPAN_PREFIX = "bench."
+# the program's spans inside a pump (``serving/engine.py``, ``libhas.py``)
+PROGRAM_SPANS = ("engine.", "libhas.acquire")
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 
@@ -31,7 +34,7 @@ OPS_LINE = "XLA Ops"
 class Trace:
     modules: List[Event]     # device programs, by start
     ops: List[Event]         # device operations, by start
-    spans: List[Event]       # the benchmark's host spans, by start
+    spans: List[Event]       # the kept host spans, by start
     busy: Optional[List[List[float]]] = None   # busy_intervals, once read
 
     def window(self) -> Tuple[float, float]:
@@ -73,7 +76,8 @@ def load(log_dir: str, device_plane: str = "/device:TPU:0") -> Trace:
             for line in plane.lines:
                 spans.extend((e.name, e.start_ns, e.start_ns + e.duration_ns)
                              for e in line.events
-                             if e.name.startswith(SPAN_PREFIX))
+                             if e.name.startswith(
+                                 (SPAN_PREFIX,) + PROGRAM_SPANS))
     for ev in (modules, ops, spans):
         ev.sort(key=lambda e: e[1])
     return Trace(modules, ops, spans)
@@ -110,15 +114,19 @@ def busy_ns(tr: Trace) -> float:
 
 
 def programs(tr: Trace, key: str) -> List[Event]:
-    """Device programs whose name holds ``key``, inside the window."""
+    """Device programs whose name holds ``key``, inside the window. The
+    device's clock runs behind the host's: a program dispatched just
+    after the window opens can start before it on the trace's clock, so
+    one that ends inside the window counts."""
     lo, hi = tr.window()
-    return [m for m in tr.modules if key in m[0] and m[1] >= lo
+    return [m for m in tr.modules if key in m[0] and m[2] > lo
             and m[1] < hi]
 
 
 def span_at(tr: Trace, t: float) -> str:
-    """Name of the innermost ``bench.*`` span open at ``t`` (the window
-    itself counts as none)."""
+    """Name of the innermost host span open at ``t``: a program span by
+    its full name (``engine.sync``), a benchmark span without its prefix
+    (``pump``); the window itself counts as none."""
     best: Optional[Event] = None
     for s in tr.spans:
         if s[1] > t:
@@ -126,7 +134,9 @@ def span_at(tr: Trace, t: float) -> str:
         if s[2] >= t and s[0] != SPAN_PREFIX + "window" and (
                 best is None or s[1] >= best[1]):
             best = s
-    return best[0][len(SPAN_PREFIX):] if best else "none"
+    if best is None:
+        return "none"
+    return best[0].removeprefix(SPAN_PREFIX)
 
 
 def grouped_by_span(tr: Trace, events: List[Event],
@@ -152,7 +162,8 @@ def gaps_between(events: List[Event]) -> List[float]:
 
 def idle_gaps(tr: Trace, n: int = 10) -> List[Tuple[str, float]]:
     """The ``n`` longest idle stretches of the device inside the window,
-    as (host span open at its middle, seconds), longest first."""
+    as (innermost host span open at its middle, seconds), longest
+    first."""
     lo, hi = tr.window()
     busy = busy_intervals(tr)
     edges = [lo] + [x for iv in busy for x in iv] + [hi]

@@ -5,6 +5,8 @@ A mix (``bench/traffic/<name>.json``) gives the arrivals, the prompt
 length, the output-length distribution and the pod that serves it:
 
     {"arrivals": {"kind": "poisson", "rate_per_s": 1.6}
+              or {"kind": "bursty", "base_per_s": 3.0, "burst_factor": 4,
+                  "period_s": 10, "burst_s": 2, "first_burst_s": 4}
               or {"kind": "backlog"},
      "prompt_len": 256,
      "output_len": {"kind": "lognormal", "median": 96, "sigma": 0.5,
@@ -24,6 +26,14 @@ how much there is to do or which requests meet in a batch:
   each seed (p95 12-17 %). What spread remains is run-to-run timing: in
   a static-batching queue a few milliseconds decide which batch a
   request waits behind.
+- ``bursty``: the window on a fixed timeline of stretches. Bursts run
+  over ``[first_burst_s + k * period_s, ... + burst_s)`` at
+  ``burst_factor * base_per_s``; the stretches between them at
+  ``base_per_s``. Each stretch holds ``round(rate * length)`` requests,
+  spaced as ``poisson`` spaces the whole window (quantile gaps shuffled
+  by ``BASE_ORDER``, scaled to the stretch); the output lengths are the
+  distribution's quantiles over all of the window's requests, shuffled
+  once. One schedule for every seed, as for ``poisson``.
 - ``backlog``: a queue that never empties. Requests come in groups of the
   pod's batch; each group holds the length distribution's ``batch``
   quantiles, in an order drawn from the seed. The batcher serves a full
@@ -36,7 +46,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from statistics import NormalDist
-from typing import Iterator, List, Optional
+from typing import Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -71,22 +81,58 @@ def _prompts(rng, n: int, length: int, vocab: int) -> np.ndarray:
     return rng.integers(1, vocab, (n, length), dtype=np.int32)
 
 
+def stretches(arrivals: dict, seconds: float,
+              rate_per_s: Optional[float] = None
+              ) -> List[Tuple[float, float, int]]:
+    """The window's stretches of one rate, as (start, end, requests), in
+    order. ``rate_per_s`` overrides the mix's rate (``poisson``) or base
+    rate (``bursty``)."""
+    kind = arrivals["kind"]
+    if kind == "poisson":
+        rate = rate_per_s or arrivals["rate_per_s"]
+        return [(0.0, seconds, max(1, round(rate * seconds)))]
+    if kind != "bursty":
+        raise ValueError(f"not an open-loop mix: {kind!r}")
+    base = rate_per_s or arrivals["base_per_s"]
+    burst = arrivals["burst_factor"] * base
+    edges, t = [], float(arrivals["first_burst_s"])
+    while t < seconds:
+        edges.append((t, min(t + arrivals["burst_s"], seconds)))
+        t += arrivals["period_s"]
+    out, prev = [], 0.0
+    for a, b in edges:
+        out += [(prev, a, base), (a, b, burst)]
+        prev = b
+    out.append((prev, seconds, base))
+    return [(a, b, round(r * (b - a))) for a, b, r in out if b > a]
+
+
+def schedule(traffic: dict, seconds: float,
+             rate_per_s: Optional[float] = None) -> Tuple[np.ndarray,
+                                                          np.ndarray]:
+    """Due times (seconds after the window opens, ascending) and output
+    lengths of an open-loop mix: the same for every seed."""
+    order = np.random.default_rng(BASE_ORDER)
+    dues = []
+    for a, b, n in stretches(traffic["arrivals"], seconds, rate_per_s):
+        if n == 0:
+            continue
+        gaps = order.permutation([-math.log(1.0 - (i + 0.5) / n)
+                                  for i in range(n)])
+        gaps *= (b - a) / gaps.sum()
+        dues.append(a + np.concatenate([[0.0], np.cumsum(gaps)[:-1]]))
+    due = np.concatenate(dues)
+    lens = order.permutation(output_lengths(traffic["output_len"],
+                                            len(due)))
+    return due, lens
+
+
 def open_loop(traffic: dict, seconds: float, seed: int, vocab: int,
               rate_per_s: Optional[float] = None) -> List[Planned]:
     """The requests due inside a window of ``seconds``, in due order.
     ``rate_per_s`` overrides the mix's rate (for a sweep)."""
-    arr = traffic["arrivals"]
-    if arr["kind"] != "poisson":
-        raise ValueError(f"not an open-loop mix: {arr['kind']!r}")
-    rate = rate_per_s or arr["rate_per_s"]
-    n = max(1, round(rate * seconds))
-    order = np.random.default_rng(BASE_ORDER)
-    gaps = order.permutation([-math.log(1.0 - (i + 0.5) / n)
-                              for i in range(n)])
-    lens = order.permutation(output_lengths(traffic["output_len"], n))
-    gaps *= seconds / gaps.sum()
-    due = np.concatenate([[0.0], np.cumsum(gaps)[:-1]])
-    prompts = _prompts(_rng(seed, 2), n, traffic["prompt_len"], vocab)
+    due, lens = schedule(traffic, seconds, rate_per_s)
+    prompts = _prompts(_rng(seed, 2), len(due), traffic["prompt_len"], vocab)
     return [Planned(float(d), p, int(m))
             for d, p, m in zip(due, prompts, lens)]
 

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import math
 import sys
+import types
 from pathlib import Path
 
 import numpy as np
@@ -26,7 +27,8 @@ from repro.serving.batcher import BatchRecord, InferenceRequest  # noqa: E402
 
 READERS = ("libhas_sleep_share", "libhas_sleep_share.offline",
            "token_turnaround_ms", "token_turnaround_ms.offline",
-           "admit_wait_p50_ms")
+           "admit_wait_p50_ms", "batch_fill")
+POD_OF_4 = types.SimpleNamespace(traffic={"pod": {"batch": 4}})
 
 
 def _read(name, run):
@@ -45,7 +47,7 @@ def _req(arrival, rec=None, max_new=2):
 
 def _run(batches, attempted):
     return cell_mod.Run(
-        cell=None, shape=None, peaks={}, t_start=0.0, t_window=0.0,
+        cell=POD_OF_4, shape=None, peaks={}, t_start=0.0, t_window=0.0,
         t_last_end=batches[-1].end if batches else 0.0,
         attempted=attempted, batches=batches, compiles_in_window=0)
 
@@ -82,6 +84,20 @@ def test_readers_on_hand_set_records():
     assert records.admit_waits_ms(run) == pytest.approx(
         [100.0, 400.0, 1000.0, math.inf])
     assert got["admit_wait_p50_ms"] == pytest.approx(700.0)
+    # rows 2 and 1 of a pod of 4
+    assert got["batch_fill"] == pytest.approx(100 * 1.5 / 4)
+
+
+def test_batch_fill_counts_the_requests_stamped_with_each_record():
+    """Rows are the attempted requests that hold a batch's record, not
+    the requests the benchmark filed under it."""
+    run = synthetic_run()
+    a = run.batches[0].reqs[0].request.batch_record
+    run.attempted.append(_req(9.95, a))      # a third row of batch a
+    assert _read("batch_fill", run) == pytest.approx(100 * 2.0 / 4)
+    full = synthetic_run()
+    full.cell = types.SimpleNamespace(traffic={"pod": {"batch": 2}})
+    assert _read("batch_fill", full) == pytest.approx(100 * 1.5 / 2)
 
 
 def test_admit_wait_median_is_inf_when_most_were_not_served():
