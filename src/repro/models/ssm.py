@@ -97,11 +97,12 @@ def ssd_forward(cfg, p, x, *, initial_state=None, return_state=False,
     proj = x @ p["in_proj"]
     z, xs, Bm, Cm, dt_raw = _split_proj(cfg, proj)
     xbc_raw = jnp.concatenate([xs, Bm, Cm], axis=-1)
-    # pre-conv window for decode (pad in case S < conv_width - 1)
-    conv_tail = jnp.pad(
-        xbc_raw, ((0, 0), (max(s.conv_width - 1 - S, 0), 0), (0, 0))
-    )[:, -(s.conv_width - 1):]
-    xbc = _causal_conv(cfg, p, xbc_raw)
+    with jax.named_scope("ssm.conv"):
+        # pre-conv window for decode (pad in case S < conv_width - 1)
+        conv_tail = jnp.pad(
+            xbc_raw, ((0, 0), (max(s.conv_width - 1 - S, 0), 0), (0, 0))
+        )[:, -(s.conv_width - 1):]
+        xbc = _causal_conv(cfg, p, xbc_raw)
     xs, Bm, Cm = jnp.split(xbc, [di, di + s.n_groups * s.d_state], axis=-1)
 
     xh = xs.reshape(B_, S, nh, s.head_dim)
@@ -124,42 +125,43 @@ def ssd_forward(cfg, p, x, *, initial_state=None, return_state=False,
     h0 = (initial_state if initial_state is not None
           else jnp.zeros((B_, nh, s.head_dim, s.d_state), jnp.float32))
 
-    if use_kernels:
-        from repro.kernels import ssd_scan
-        final, yc = ssd_scan.ssd_chunk_scan(xc, Bc, Cc, dtc, dAc, h0)
-        y = yc.transpose(1, 0, 2, 3, 4).reshape(B_, S, nh, s.head_dim)
-    else:
-        def body(h, xs_):
-            x_i, B_i, C_i, dt_i, dA_i = xs_
-            cum = jnp.cumsum(dA_i, axis=1)          # (B,Q,nh)
-            total = cum[:, -1]                      # (B,nh)
-            # intra-chunk dual (quadratic, attention-like) form
-            cb = jnp.einsum("bihn,bjhn->bhij", C_i.astype(jnp.float32),
-                            B_i.astype(jnp.float32))           # (B,nh,Q,Q)
-            li = cum.transpose(0, 2, 1)[:, :, :, None]         # (B,nh,Q,1)
-            lj = cum.transpose(0, 2, 1)[:, :, None, :]         # (B,nh,1,Q)
-            # mask BEFORE exp: the i<j branch would overflow and poison
-            # gradients through the where
-            diff = jnp.where(jnp.tril(jnp.ones((Q, Q), bool)),
-                             li - lj, -1e30)
-            decay = jnp.exp(diff)
-            scores = cb * decay * dt_i.transpose(0, 2, 1)[:, :, None, :]
-            y_intra = jnp.einsum("bhij,bjhp->bihp", scores,
-                                 x_i.astype(jnp.float32))
-            # carried-state contribution
-            y_inter = jnp.einsum("bihn,bhpn->bihp",
-                                 C_i.astype(jnp.float32)
-                                 * jnp.exp(cum)[..., None], h)
-            # state update
-            w = dt_i * jnp.exp(total[:, None, :] - cum)        # (B,Q,nh)
-            dstate = jnp.einsum("bjhp,bjhn->bhpn",
-                                x_i.astype(jnp.float32) * w[..., None],
-                                B_i.astype(jnp.float32))
-            h_new = jnp.exp(total)[:, :, None, None] * h + dstate
-            return h_new, y_intra + y_inter
+    with jax.named_scope("ssm.chunk_scan"):
+        if use_kernels:
+            from repro.kernels import ssd_scan
+            final, yc = ssd_scan.ssd_chunk_scan(xc, Bc, Cc, dtc, dAc, h0)
+            y = yc.transpose(1, 0, 2, 3, 4).reshape(B_, S, nh, s.head_dim)
+        else:
+            def body(h, xs_):
+                x_i, B_i, C_i, dt_i, dA_i = xs_
+                cum = jnp.cumsum(dA_i, axis=1)          # (B,Q,nh)
+                total = cum[:, -1]                      # (B,nh)
+                # intra-chunk dual (quadratic, attention-like) form
+                cb = jnp.einsum("bihn,bjhn->bhij", C_i.astype(jnp.float32),
+                                B_i.astype(jnp.float32))           # (B,nh,Q,Q)
+                li = cum.transpose(0, 2, 1)[:, :, :, None]         # (B,nh,Q,1)
+                lj = cum.transpose(0, 2, 1)[:, :, None, :]         # (B,nh,1,Q)
+                # mask BEFORE exp: the i<j branch would overflow and poison
+                # gradients through the where
+                diff = jnp.where(jnp.tril(jnp.ones((Q, Q), bool)),
+                                 li - lj, -1e30)
+                decay = jnp.exp(diff)
+                scores = cb * decay * dt_i.transpose(0, 2, 1)[:, :, None, :]
+                y_intra = jnp.einsum("bhij,bjhp->bihp", scores,
+                                     x_i.astype(jnp.float32))
+                # carried-state contribution
+                y_inter = jnp.einsum("bihn,bhpn->bihp",
+                                     C_i.astype(jnp.float32)
+                                     * jnp.exp(cum)[..., None], h)
+                with jax.named_scope("ssm.state_update"):
+                    w = dt_i * jnp.exp(total[:, None, :] - cum)    # (B,Q,nh)
+                    dstate = jnp.einsum("bjhp,bjhn->bhpn",
+                                        x_i.astype(jnp.float32) * w[..., None],
+                                        B_i.astype(jnp.float32))
+                    h_new = jnp.exp(total)[:, :, None, None] * h + dstate
+                return h_new, y_intra + y_inter
 
-        final, yc = jax.lax.scan(body, h0, (xc, Bc, Cc, dtc, dAc))
-        y = yc.transpose(1, 0, 2, 3, 4).reshape(B_, S, nh, s.head_dim)
+            final, yc = jax.lax.scan(body, h0, (xc, Bc, Cc, dtc, dAc))
+            y = yc.transpose(1, 0, 2, 3, 4).reshape(B_, S, nh, s.head_dim)
 
     y = y + p["D"][None, None, :, None] * xh.astype(jnp.float32)
     y = _gated_norm(p, y.reshape(B_, S, di), z)
@@ -179,11 +181,12 @@ def ssd_decode_step(cfg, p, x, conv_state, ssm_state):
     proj = x @ p["in_proj"]
     z, xs, Bm, Cm, dt_raw = _split_proj(cfg, proj)
     xbc = jnp.concatenate([xs, Bm, Cm], axis=-1)  # (B,1,C)
-    window = jnp.concatenate([conv_state, xbc], axis=1)  # (B,W,C)
-    new_conv_state = window[:, 1:]
-    conv_out = jnp.einsum("bwc,wc->bc", window.astype(jnp.float32),
-                          p["conv_w"].astype(jnp.float32)) + p["conv_b"].astype(jnp.float32)
-    conv_out = jax.nn.silu(conv_out)[:, None, :].astype(x.dtype)
+    with jax.named_scope("ssm.conv"):
+        window = jnp.concatenate([conv_state, xbc], axis=1)  # (B,W,C)
+        new_conv_state = window[:, 1:]
+        conv_out = jnp.einsum("bwc,wc->bc", window.astype(jnp.float32),
+                              p["conv_w"].astype(jnp.float32)) + p["conv_b"].astype(jnp.float32)
+        conv_out = jax.nn.silu(conv_out)[:, None, :].astype(x.dtype)
     xs, Bm, Cm = jnp.split(conv_out, [di, di + s.n_groups * s.d_state], axis=-1)
 
     xh = xs.reshape(B_, nh, s.head_dim).astype(jnp.float32)
@@ -192,11 +195,12 @@ def ssd_decode_step(cfg, p, x, conv_state, ssm_state):
     Ch = jnp.repeat(Cm.reshape(B_, s.n_groups, s.d_state), hpg, axis=1)
     dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32) + p["dt_bias"])  # (B,nh)
     A = -jnp.exp(p["A_log"])
-    a = jnp.exp(dt * A)  # (B,nh)
-    dstate = jnp.einsum("bhp,bhn->bhpn", xh * dt[..., None],
-                        Bh.astype(jnp.float32))
-    new_state = a[:, :, None, None] * ssm_state + dstate
-    y = jnp.einsum("bhpn,bhn->bhp", new_state, Ch.astype(jnp.float32))
+    with jax.named_scope("ssm.state_update"):
+        a = jnp.exp(dt * A)  # (B,nh)
+        dstate = jnp.einsum("bhp,bhn->bhpn", xh * dt[..., None],
+                            Bh.astype(jnp.float32))
+        new_state = a[:, :, None, None] * ssm_state + dstate
+        y = jnp.einsum("bhpn,bhn->bhp", new_state, Ch.astype(jnp.float32))
     y = y + p["D"][None, :, None] * xh
     y = _gated_norm(p, y.reshape(B_, 1, di), z)
     return y.astype(x.dtype) @ p["out_proj"], new_conv_state, new_state

@@ -27,6 +27,9 @@ class BatchRecord:
     # decode steps: from the host copy of the previous token to the
     # return of the next decode launch, less that launch's sleep
     turnaround_s: float = 0.0
+    # bytes of recurrent state (SSM conv window and state) that the
+    # batch's cache holds; 0 for an attention-only model
+    state_bytes: int = 0
 
 
 @dataclasses.dataclass

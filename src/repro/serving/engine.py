@@ -19,7 +19,8 @@ batch, and inside it ``engine.prefill`` and ``engine.decode`` around
 each launch, ``engine.sample`` around each eager argmax and
 ``engine.sync`` around each host copy of a token and the wait for the
 last decode; each libhas charge adds ``libhas.acquire``. Each served
-request holds the batch's ``BatchRecord``.
+request holds the batch's ``BatchRecord``, which also counts the bytes
+of recurrent state in the batch's cache.
 """
 from __future__ import annotations
 
@@ -57,6 +58,14 @@ def compiled_steps(cfg: ArchConfig, max_seq: int, opts: CallOpts) -> tuple:
     ``jit_prefill_step`` and ``jit_decode_step``."""
     return (jax.jit(steps.make_prefill_step(cfg, max_seq, opts)),
             jax.jit(steps.make_decode_step(cfg, opts)))
+
+
+def state_bytes(cache) -> int:
+    """Bytes of the recurrent-state leaves (``conv``, ``state``) of a
+    cache, from their shapes: no device sync."""
+    return sum(leaf.size * leaf.dtype.itemsize
+               for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+               if getattr(path[-1], "key", None) in ("conv", "state"))
 
 
 def _sample(logits) -> jax.Array:
@@ -136,6 +145,7 @@ class PodEngine:
             with jax.profiler.TraceAnnotation("engine.prefill"):
                 logits, cache = libhas.launch(
                     self._prefill, self.params, batch, cost_s=0.0)
+            rec.state_bytes = state_bytes(cache)
             tok = _sample(logits)
             outs = np.zeros((B, rec.steps), np.int32)
             for i in range(rec.steps):
